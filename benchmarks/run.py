@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
@@ -197,6 +198,27 @@ def main():
     trials = 2 if args.quick else 3
     t0 = time.time()
 
+    if not args.skip_roofline:
+        # a compile-only dry-run on virtual CPU devices: pinned to the CPU
+        # so it never asks for an accelerator, and run before this process
+        # touches JAX
+        print("=" * 72)
+        print("§Roofline — per-(arch × shape) terms from the compiled "
+              "dry-run (512-device subprocess)")
+        print("=" * 72)
+        sys.stdout.flush()  # keep tee ordering across the subprocess
+        cmd = [sys.executable, "-m", "benchmarks.roofline"]
+        for a in (args.roofline_arch or []):
+            cmd += ["--arch", a]
+        if args.quick:
+            for a in ("qwen3-14b", "olmoe-1b-7b", "mamba2-2.7b"):
+                cmd += ["--arch", a]
+        r = subprocess.run(cmd, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+        if r.returncode != 0:
+            print("roofline subprocess failed", file=sys.stderr)
+            return 1
+        print()
+
     from benchmarks import (fig5_speedup, fig6_trace, fig7_overhead,
                             fig8_scaling, fig10_sync_offload,
                             fig11_effect_domains, fig12_autobatch,
@@ -287,23 +309,6 @@ def main():
         fig8_scaling.run(trials=1, beams=(1, 5, 10), assessments=(1, 5, 10))
     else:
         fig8_scaling.run(trials=trials)
-
-    if not args.skip_roofline:
-        print("\n" + "=" * 72)
-        print("§Roofline — per-(arch × shape) terms from the compiled "
-              "dry-run (512-device subprocess)")
-        print("=" * 72)
-        sys.stdout.flush()  # keep tee ordering across the subprocess
-        cmd = [sys.executable, "-m", "benchmarks.roofline"]
-        for a in (args.roofline_arch or []):
-            cmd += ["--arch", a]
-        if args.quick:
-            for a in ("qwen3-14b", "olmoe-1b-7b", "mamba2-2.7b"):
-                cmd += ["--arch", a]
-        r = subprocess.run(cmd)
-        if r.returncode != 0:
-            print("roofline subprocess failed", file=sys.stderr)
-            return 1
 
     print(f"\nall benchmarks done in {time.time()-t0:.0f}s")
     return 0

@@ -19,6 +19,7 @@ from repro.configs import get_config
 from repro.core import poppy, sequential
 from repro.core.ai import llm, use_dispatcher
 from repro.dispatch import AdmissionPolicy, Dispatcher, HedgePolicy
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.serving import LocalEngineBackend, ServingEngine
 
@@ -47,9 +48,10 @@ def main():
     ap.add_argument("--docs", type=int, default=4)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch).reduced()
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = model.init(jax.random.PRNGKey(0), cfg.serve_param_dtype)
     # max_len must cover the longest prompt (the combine call grows with
     # --docs) plus decode room — the engine rejects prompts that don't fit
     engine = ServingEngine(model, params, max_slots=4, max_len=256,

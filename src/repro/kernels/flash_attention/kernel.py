@@ -53,8 +53,10 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         v = v_ref[0, 0].astype(jnp.float32)
         # zero OOB v rows: block padding may be NaN and 0·NaN = NaN in the
         # p@v reduction
-        vrow = k_start + jax.lax.iota(jnp.int32, block_kv)
-        v = jnp.where((vrow < seq_kv)[:, None], v, 0.0)
+        # (a 2-D iota: Mosaic cannot reshape a 1-D mask into a column)
+        vrow = k_start + jax.lax.broadcasted_iota(jnp.int32,
+                                                  (block_kv, v.shape[1]), 0)
+        v = jnp.where(vrow < seq_kv, v, 0.0)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [bq, bkv]

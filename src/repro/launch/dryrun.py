@@ -168,8 +168,6 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # older JAX wraps the dict in a list
-            ca = ca[0] if ca else {}
         ma = compiled.memory_analysis()
         hlo = compiled.as_text()
         coll = collective_stats(hlo)

@@ -11,6 +11,15 @@ device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices):
+    # Auto axes: the sharding rules place arrays with NamedSharding and
+    # with_sharding_constraint, which Explicit axes (make_mesh's default
+    # under JAX 0.9) reject
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, have {len(jax.devices())}; "
             "the dry-run sets --xla_force_host_platform_device_count=512 "
             "before importing jax")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return _mesh(shape, axes, devices)
 
 
 def make_serving_mesh(tp: int = 1, *, devices=None):
@@ -44,13 +53,12 @@ def make_serving_mesh(tp: int = 1, *, devices=None):
             f"serving mesh tp={tp} needs {tp} devices, have {len(pool)}; "
             "set XLA_FLAGS=--xla_force_host_platform_device_count before "
             "importing jax to simulate more on CPU")
-    return jax.make_mesh((1, tp), ("data", "model"), devices=pool[:tp])
+    return _mesh((1, tp), ("data", "model"), pool[:tp])
 
 
 def make_host_mesh():
     """A trivial 1-device mesh for CPU smoke/integration runs."""
-    return jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+    return _mesh((1, 1), ("data", "model"), jax.devices()[:1])
 
 
 def hardware_constants():

@@ -1,11 +1,13 @@
 """Serving launcher: bring up the continuous-batching engine (or a routed
-replica fleet) on a reduced config and run a demo workload of concurrent
-requests through it.
+replica fleet) and run a demo workload of concurrent requests through it.
+The model is the reduced config unless ``--full`` asks for the published
+widths; parameters are random, made in ``cfg.serve_param_dtype``.
 
     python -m repro.launch.serve --arch stablelm-3b --requests 8
     python -m repro.launch.serve --replicas 4 --router-policy prefix_affinity
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python -m repro.launch.serve --tp 2 --replicas 2
+    python -m repro.launch.serve --full      # published widths, on a chip
 """
 
 from __future__ import annotations
@@ -21,6 +23,8 @@ def main():
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new-tokens", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
+    ap.add_argument("--full", action="store_true",
+                    help="published widths instead of the reduced config")
     ap.add_argument("--tp", type=int, default=1,
                     help="devices per engine (tensor parallelism)")
     ap.add_argument("--replicas", type=int, default=1,
@@ -32,13 +36,17 @@ def main():
 
     import jax
     from repro.configs import get_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import build_model
     from repro.serving.fleet import EngineFleet
     from repro.serving.tokenizer import ByteTokenizer
 
-    cfg = get_config(args.arch).reduced()
+    use_compile_cache()
+    cfg = get_config(args.arch)
+    if not args.full:
+        cfg = cfg.reduced()
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = model.init(jax.random.PRNGKey(0), cfg.serve_param_dtype)
     fleet = EngineFleet(model, params, replicas=args.replicas, tp=args.tp,
                         policy=args.router_policy, max_slots=args.slots,
                         max_len=128)

@@ -162,7 +162,8 @@ def full_attention(cfg, p, x, *, positions, kv_x=None, causal=True,
         — x is then the prompt *suffix* whose queries attend the prefix
         keys plus their own causal keys.  ``return_kv`` returns only the
         suffix K/V (the caller already owns the prefix).  Requires
-        ``causal`` and global attention (window == 0).
+        ``causal`` and global attention (window == 0).  With Tpad == 0
+        it is ordinary causal attention (and takes the kernel path).
     """
     B, S, D = x.shape
     q = _project_q(cfg, p, x)
@@ -180,7 +181,9 @@ def full_attention(cfg, p, x, *, positions, kv_x=None, causal=True,
     k = shard_hint(shard_hint(k, "act_qkv"), "act_kv")
     v = shard_hint(shard_hint(v, "act_qkv"), "act_kv")
 
-    if prefix_kv is not None:
+    # an empty prefix (nothing cached yet) is plain causal attention over
+    # the prompt, which the kernel below serves
+    if prefix_kv is not None and prefix_kv[0].shape[1] > 0:
         assert causal and window == 0 and kv_x is None, \
             "prefix attention is causal global self-attention only"
         pk, pv = prefix_kv
@@ -310,18 +313,17 @@ def decode_attention(cfg, p, x, cache, positions, *, window=0):
         else:
             new_cache[name] = _write_slot(cache[name], new, slots)
     impl = cfg.attention_impl
+    # validity is a prefix of the cache: a full cache holds slots
+    # [0, pos]; a ring holds positions (pos-C, pos], i.e. every slot once
+    # pos >= C
+    lengths = jnp.minimum(positions + 1, C)
     if cfg.kv_cache_dtype == "int8":
         if impl.startswith("pallas"):
             # in-kernel dequantization: HBM reads stay int8
             from repro.kernels.decode_attention import ops as da_ops
-            j = jnp.arange(C)[None, :]
-            if window > 0:
-                valid = (j <= positions[:, None]) | (positions[:, None] >= C)
-            else:
-                valid = j <= positions[:, None]
             out = da_ops.decode_attention_int8(
                 q, new_cache["k"], new_cache["v"], new_cache["k_scale"],
-                new_cache["v_scale"], valid,
+                new_cache["v_scale"], lengths,
                 interpret=(impl == "pallas_interpret"))
             out = shard_hint(out, "act_qkv")
             out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
@@ -331,21 +333,14 @@ def decode_attention(cfg, p, x, cache, positions, *, window=0):
     else:
         ck, cv = new_cache["k"], new_cache["v"]
 
-    # validity: full cache → slot j valid iff j <= pos;
-    # ring → slot valid iff it holds a position in (pos-C, pos]
-    j = jnp.arange(C)[None, :]
-    if window > 0:
-        valid = (j <= positions[:, None]) | (positions[:, None] >= C)
-    else:
-        valid = j <= positions[:, None]
-    mask = valid[:, None, None, :]  # [B,1,1,C] → broadcast over (k-heads, S)
-
     if impl.startswith("pallas"):
         from repro.kernels.decode_attention import ops as da_ops
         out = da_ops.decode_attention(
-            q, ck, cv, valid, interpret=(impl == "pallas_interpret"))
+            q, ck, cv, lengths, interpret=(impl == "pallas_interpret"))
     else:
-        out = mha_reference(q, ck, cv, mask=mask)
+        valid = jnp.arange(C)[None, :] < lengths[:, None]
+        # [B,1,1,C] → broadcast over (k-heads, S)
+        out = mha_reference(q, ck, cv, mask=valid[:, None, None, :])
     out = shard_hint(out, "act_qkv")
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return out, new_cache

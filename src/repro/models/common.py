@@ -10,6 +10,7 @@ can never drift apart.
 from __future__ import annotations
 
 import math
+import zlib
 from dataclasses import dataclass
 
 import jax
@@ -25,8 +26,9 @@ class PSpec:
 
 
 def _path_rng(rng, path: str):
-    h = hash(path) & 0x7FFFFFFF
-    return jax.random.fold_in(rng, h)
+    # crc32, not hash(): str hashes are salted per process, and the same
+    # seed must give the same weights in every run
+    return jax.random.fold_in(rng, zlib.crc32(path.encode()) & 0x7FFFFFFF)
 
 
 def init_param(rng, path: str, spec: PSpec, dtype):

@@ -21,8 +21,13 @@ class Model:
             return encdec.encdec_schema(self.cfg)
         return lm.lm_schema(self.cfg)
 
-    def init(self, rng):
-        return init_tree(rng, self.schema(), jnp.dtype(self.cfg.param_dtype))
+    def init(self, rng, dtype=None):
+        """Random parameters from ``rng``, created directly in ``dtype``
+        (default ``cfg.param_dtype``).  Serving passes
+        ``cfg.serve_param_dtype``, so a bf16 server never holds an f32
+        copy of its weights."""
+        return init_tree(rng, self.schema(),
+                         jnp.dtype(dtype or self.cfg.param_dtype))
 
     def abstract_params(self, dtype=None):
         return abstract_tree(self.schema(), dtype or self.cfg.param_dtype)

@@ -356,9 +356,8 @@ class ServingEngine:
             self.allocator = None
             self._wait_pages: list[Request] = []
             self.page_op_shapes: set = set()
-            self.cache = self._place_cache(model.init_cache(max_slots,
-                                                            max_len),
-                                           "contiguous")
+            self.cache = self._new_cache(
+                lambda: model.init_cache(max_slots, max_len), "contiguous")
 
             def _decode_fn(p, cache, toks, pos):
                 logits, cache = model.decode_step(p, cache, toks, pos)
@@ -403,9 +402,9 @@ class ServingEngine:
         self.allocator = PageAllocator(self.num_pages, page_size,
                                        metrics=self.metrics)
         # pool leaf shape: [n_groups, num_pages+1, page_size, KVH, hd]
-        self.kv_pages = self._place_cache(
-            self.model.init_paged_cache(self.num_pages + 1, page_size),
-            "paged")
+        self.kv_pages = self._new_cache(
+            lambda: self.model.init_paged_cache(self.num_pages + 1,
+                                                page_size), "paged")
         self._page_table = np.zeros((self.max_slots, self.pages_per_slot),
                                     np.int32)
         self._table_dev = jnp.asarray(self._page_table)
@@ -492,13 +491,19 @@ class ServingEngine:
         return jax.tree.map(jax.lax.with_sharding_constraint,
                             tree, shardings)
 
-    def _place_cache(self, tree, layout: str):
-        """Device placement for a freshly initialized cache/pool."""
+    def _new_cache(self, make, layout: str):
+        """A freshly initialized cache/pool, ``make()``, built by one
+        compiled program.  Run eagerly, each stacked leaf would pass
+        through a second full-size copy (broadcast, then copy).  Under a
+        mesh it is made in place, on the mesh's devices in its canonical
+        layout: made on the default device and then moved, it would take
+        that device's memory for a while, and a pool sized for TP may not
+        fit there."""
         if self._rules is None:
-            return tree
-        return jax.device_put(
-            tree, named(self._rules,
-                        cache_pspecs(self._rules, tree, layout=layout)))
+            return jax.jit(make)()
+        shardings = named(self._rules, cache_pspecs(
+            self._rules, jax.eval_shape(make), layout=layout))
+        return jax.jit(make, out_shardings=shardings)()
 
     def _tr(self, track: str) -> str:
         """Observability track name, replica-prefixed when the engine is
@@ -563,9 +568,22 @@ class ServingEngine:
             sp.attrs["n_out"] = len(out)
             return out
 
+    def prompt_logits(self, tokens):
+        """Logits [1, V] for the token after ``tokens``, through the
+        engine's own jitted prefill with nothing cached: the distribution
+        a fresh request's first token is drawn from.  For checking the
+        serving path against a direct ``Model.prefill``."""
+        toks = list(tokens)
+        if self._paged:
+            logits, _ = self._run_prefill(toks, None, 0)
+        else:
+            logits, _ = self._prefill_exact(
+                self.params, {"tokens": jnp.asarray([toks], jnp.int32)})
+        return logits
+
     def _wake_event(self) -> asyncio.Event:
-        # py3.10 asyncio primitives bind to their first loop; the engine
-        # outlives benchmark/test loops, so the event is per-loop
+        # asyncio primitives bind to the loop they are first used on; the
+        # engine outlives benchmark/test loops, so the event is per-loop
         loop = asyncio.get_running_loop()
         if self._wake is None or self._wake_loop is not loop:
             self._wake = asyncio.Event()

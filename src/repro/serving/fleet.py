@@ -9,8 +9,9 @@ a ``Backend``).
 
 Device carving: replica ``i`` takes the ``tp`` devices starting at
 ``i * tp`` when the host has that many, so fleet replicas run on disjoint
-meshes (the CPU-virtual-device CI leg exercises exactly this).  When the
-host is too small the replicas share the first ``tp`` devices — on a
+meshes — at ``tp=1`` too, where each replica's mesh is its one device
+(four replicas on a four-chip host hold one chip each).  When the host
+is too small the replicas share the first ``tp`` devices — on a
 single-process simulation they time-share anyway, and scheduling (slots,
 queues, page pools) is still fully per-replica.
 
@@ -35,11 +36,10 @@ from repro.serving.engine import ServingEngine
 class EngineFleet:
     """N serving-engine replicas behind a prefix-affinity router.
 
-    ``replicas`` engines are built from one ``(model, params)`` pair
-    (params are shared host-side; each mesh-placed replica holds its own
-    device copy).  ``tp`` > 1 gives every replica its own
-    ``make_serving_mesh(tp)`` over a disjoint device slice when the host
-    has ``replicas * tp`` devices.  Remaining keyword arguments go to
+    ``replicas`` engines are built from one ``(model, params)`` pair;
+    each replica places its own copy on its own
+    ``make_serving_mesh(tp)``, a disjoint device slice when the host has
+    ``replicas * tp`` devices.  Remaining keyword arguments go to
     every ``ServingEngine``; ``dispatcher_kwargs`` (e.g. ``cache=``,
     ``hedge=``) go to the fleet's ``Dispatcher``.
     """
@@ -61,14 +61,12 @@ class EngineFleet:
         self.names = [f"replica{i}" for i in range(replicas)]
         self.engines: list[ServingEngine] = []
         for i, name in enumerate(self.names):
-            mesh = None
-            if tp > 1:
-                lo = i * tp
-                sl = devices[lo:lo + tp] if lo + tp <= len(devices) \
-                    else devices[:tp]
-                mesh = make_serving_mesh(tp, devices=sl)
+            lo = i * tp
+            sl = devices[lo:lo + tp] if lo + tp <= len(devices) \
+                else devices[:tp]
             self.engines.append(ServingEngine(
-                model, params, mesh=mesh, name=name, **engine_kwargs))
+                model, params, mesh=make_serving_mesh(tp, devices=sl),
+                name=name, **engine_kwargs))
         self.backends = [
             LocalEngineBackend(e, tokenizer, hedge_timeout=hedge_timeout)
             for e in self.engines]

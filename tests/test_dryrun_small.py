@@ -17,14 +17,14 @@ DRYRUN_SMALL = textwrap.dedent("""
     from repro.configs import get_config, SHAPES, ShapeSpec
     from repro.launch.steps import lower_cell
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = get_config("{arch}").reduced().replace(vocab_size=512)
     shape = ShapeSpec("t", {seq}, {batch}, "{kind}")
     lowered, model, rls = lower_cell(cfg, shape, mesh)
     compiled = lowered.compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # older JAX: one dict per device
-        ca = ca[0]
     assert ca.get("flops", 0) > 0
     print("OK", rls.tp_strategy, int(ca["flops"]))
 """)
@@ -55,7 +55,8 @@ def test_sharding_rules_divisibility_fallback():
     from repro.sharding import rules as R
 
     mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+                         devices=jax.devices()[:1],
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_config("qwen3-14b")
     rls = R.make_rules(mesh, cfg)
     # everything divides by 1 → specs resolve

@@ -29,14 +29,16 @@ DRILL = textwrap.dedent("""
 
     # "pod A": 4×2 mesh
     mesh_a = jax.make_mesh((4, 2), ("data", "model"),
-                           devices=jax.devices()[:8])
+                           devices=jax.devices()[:8],
+                           axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rls_a = R.make_rules(mesh_a, cfg)
     state = init_train_state(model, opt, jax.random.PRNGKey(0))
     with tempfile.TemporaryDirectory() as d:
         ckpt.save(d, 5, state)
 
         # "pod B": different shape (2×2×4 multi-pod-style), different devices
-        mesh_b = jax.make_mesh((2, 2, 4), ("pod", "data", "model"))
+        mesh_b = jax.make_mesh((2, 2, 4), ("pod", "data", "model"),
+                               axis_types=(jax.sharding.AxisType.Auto,) * 3)
         rls_b = R.make_rules(mesh_b, cfg)
         specs = train_state_pspecs(rls_b, model, opt)
         shardings = jax.tree.map(

@@ -85,8 +85,9 @@ def test_decode_attention_matches_ref(B, C, H, KVH, d, fill, dtype):
     q = jax.random.normal(kq, (B, 1, H, d), dtype)
     k = jax.random.normal(kk, (B, C, KVH, d), dtype)
     v = jax.random.normal(kv, (B, C, KVH, d), dtype)
-    valid = jnp.arange(C)[None, :] < jnp.array([[fill]] * B)
-    out = da_ops.decode_attention(q, k, v, valid, interpret=True)
+    lengths = jnp.full((B,), fill, jnp.int32)
+    valid = jnp.arange(C)[None, :] < lengths[:, None]
+    out = da_ops.decode_attention(q, k, v, lengths, interpret=True)
     ref = decode_attention_ref(q, k, v, valid)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
@@ -139,6 +140,32 @@ def test_paged_decode_attention_matches_ref(B, ps, N, H, KVH, d, lengths,
     np.testing.assert_allclose(
         np.asarray(out_xla, np.float32), np.asarray(ref, np.float32),
         **tol(dtype))
+
+
+@pytest.mark.parametrize("lengths,window", [((64, 37), 0), ((50, 1), 24)])
+def test_paged_decode_attention_ignores_nonfinite_stale_slots(lengths,
+                                                              window):
+    """Pool slots no sequence may read (a recycled page's tail, scratch
+    page 0, spare pages) can hold NaN or inf; the kernel's output must be
+    what the clean pool gives."""
+    B, ps, N, H, KVH, d = 2, 16, 4, 4, 2, 32
+    q, k_pages, v_pages, page_table = _paged_case(B, ps, N, H, KVH, d,
+                                                  jnp.float32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    pos = jnp.arange(N * ps).reshape(1, N, ps)
+    lo = jnp.maximum(lens - window, 0) if window else jnp.zeros_like(lens)
+    readable = (pos < lens[:, None, None]) & (pos >= lo[:, None, None])
+    live = jnp.zeros(k_pages.shape[:2], bool).at[page_table].max(readable)
+    junk = jnp.where(jnp.arange(ps) % 2 == 0, jnp.nan, jnp.inf)
+    poison = jnp.where(live[..., None, None], 0.0,
+                       junk[None, :, None, None])
+    out = pa_ops.paged_decode_attention(q, k_pages + poison,
+                                        v_pages + poison, page_table, lens,
+                                        window=window, interpret=True)
+    ref = paged_decode_attention_ref(q, k_pages, v_pages, page_table, lens,
+                                     window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               **tol(jnp.float32))
 
 
 def test_paged_decode_attention_equals_contiguous():
@@ -261,6 +288,7 @@ def test_model_pallas_interpret_matches_xla(arch):
 @pytest.mark.parametrize("B,C,H,KVH,d,fill", [
     (2, 256, 4, 2, 32, 200),
     (1, 512, 8, 8, 64, 300),
+    (1, 384, 4, 2, 32, 300),    # ragged C: the last block reads padding
 ])
 def test_decode_attention_int8_matches_dequant_ref(B, C, H, KVH, d, fill):
     """int8-KV kernel (in-kernel dequant) vs reference over the
@@ -274,8 +302,9 @@ def test_decode_attention_int8_matches_dequant_ref(B, C, H, KVH, d, fill):
     v = jax.random.normal(kv, (B, C, KVH, d), jnp.float32)
     qk, sk = quantize_kv(k)
     qv, sv = quantize_kv(v)
-    valid = jnp.arange(C)[None, :] < jnp.array([[fill]] * B)
-    out = da_ops.decode_attention_int8(q, qk, qv, sk, sv, valid,
+    lengths = jnp.full((B,), fill, jnp.int32)
+    valid = jnp.arange(C)[None, :] < lengths[:, None]
+    out = da_ops.decode_attention_int8(q, qk, qv, sk, sv, lengths,
                                        interpret=True)
     kd = dequantize_kv(qk, sk, jnp.float32)
     vd = dequantize_kv(qv, sv, jnp.float32)

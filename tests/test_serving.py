@@ -189,3 +189,34 @@ def test_traced_serving_spans(served):
     admits = [e for e in trz.instants if e.cat == "serving.admit"]
     assert len(admits) == len(prompts)
     assert {e.parent_id for e in admits} <= req_ids
+
+
+@pytest.mark.parametrize("kv_layout", ["paged", "contiguous"])
+def test_prompt_logits_match_direct_prefill(served, kv_layout):
+    """The engine's first-token logits (bucket-padded jitted prefill) equal
+    an exact-length ``Model.prefill`` of the same prompt."""
+    cfg, model, params = served
+    engine = ServingEngine(model, params, max_slots=2, max_len=64,
+                           kv_layout=kv_layout)
+    prompt = [5, 17, 31, 2, 99, 4, 8]          # pads to the 16 bucket
+    got = engine.prompt_logits(prompt)
+    want, _ = model.prefill(params, {"tokens": jnp.asarray([prompt])},
+                            capacity=len(prompt))
+    assert got.shape == want.shape == (1, cfg.vocab_padded)
+    assert jnp.allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_init_in_serving_dtype(served):
+    """``Model.init(rng, dtype)`` makes every leaf directly in ``dtype``,
+    at the scale of the default dtype's draws, and the same seed gives
+    the same weights."""
+    cfg, model, _ = served
+    f32 = model.init(jax.random.PRNGKey(3))
+    bf16 = model.init(jax.random.PRNGKey(3), cfg.serve_param_dtype)
+    again = model.init(jax.random.PRNGKey(3), cfg.serve_param_dtype)
+    for a, b, c in zip(jax.tree.leaves(f32), jax.tree.leaves(bf16),
+                       jax.tree.leaves(again)):
+        assert b.dtype == jnp.bfloat16 and b.shape == a.shape
+        assert bool(jnp.all(b == c))
+        sa, sb = float(jnp.std(a)), float(jnp.std(b.astype(jnp.float32)))
+        assert abs(sa - sb) <= 0.1 * sa + 1e-6, (sa, sb)

@@ -86,7 +86,8 @@ def test_elastic_restore_new_sharding(tiny, tmp_path):
     state = init_train_state(tiny, opt, jax.random.PRNGKey(1))
     ckpt.save(tmp_path / "ck", 3, state)
     mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+                         devices=jax.devices()[:1],
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     shardings = jax.tree.map(
         lambda _: NamedSharding(mesh, P()), state)
     restored, _ = ckpt.restore(tmp_path / "ck", state, shardings=shardings)
@@ -102,7 +103,8 @@ COMPRESSION_DRILL = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.training.compression import (
         make_compressed_dp_allreduce, init_error_buffers, ef_compress_psum)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(0)
     grads = {"w": jnp.asarray(rng.normal(size=(64, 32)), jnp.float32),
              "b": jnp.asarray(rng.normal(size=(128,)), jnp.float32)}
