@@ -43,6 +43,7 @@ from pathlib import Path
 
 import jax
 
+from repro import obs
 from repro.core import batching, equivalent, poppy, recording, \
     sequential_mode
 from repro.core.ai import llm, use_backend, use_dispatcher
@@ -105,8 +106,14 @@ def build(arch="stablelm-3b", *, layout: str):
 
 
 def _run_once(mode, backend, prefix, queries):
+    """Result, seconds, trace, and the engine's ``decode.step`` spans of
+    the run (recorded on the ``--trace-out`` tracer, else on one of its
+    own)."""
     d = Dispatcher()
-    with use_backend(backend), use_dispatcher(d), recording() as tr:
+    trz = obs.current_tracer() or obs.Tracer()
+    n0 = len(trz.spans)
+    with obs.tracing(trz), use_backend(backend), use_dispatcher(d), \
+            recording() as tr:
         t0 = time.perf_counter()
         if mode == "plain":
             with sequential_mode():
@@ -115,7 +122,9 @@ def _run_once(mode, backend, prefix, queries):
             with batching():
                 result = fanout(prefix, queries)
         dt = time.perf_counter() - t0
-    return result, dt, tr
+    steps = [s for s in trz.spans[n0:]
+             if s.name == "decode.step" and not s.open]
+    return result, dt, tr, steps
 
 
 def _assert_compile_bounds(eng, label):
@@ -151,15 +160,13 @@ def bench(n=N_FANOUT, *, trials=3, prefix_chars=PREFIX_CHARS):
     for _ in range(trials):
         for eng in (eng_ct, eng_pg):
             eng.reset_prefix_cache()  # cold radix cache every trial
-        marks = {"contig": (len(eng_ct.batch_occupancy),
-                            len(eng_ct.decode_step_s)),
-                 "paged": (len(eng_pg.batch_occupancy),
-                           len(eng_pg.decode_step_s))}
-        r_ref, dt, tr_ref = _run_once("plain", be_ct, prefix, queries)
+        r_ref, dt, tr_ref, _ = _run_once("plain", be_ct, prefix, queries)
         times["plain"].append(dt)
-        r_ct, dt, tr_ct = _run_once("contig", be_ct, prefix, queries)
+        r_ct, dt, tr_ct, steps_ct = _run_once("contig", be_ct, prefix,
+                                              queries)
         times["contig"].append(dt)
-        r_pg, dt, tr_pg = _run_once("paged", be_pg, prefix, queries)
+        r_pg, dt, tr_pg, steps_pg = _run_once("paged", be_pg, prefix,
+                                              queries)
         times["paged"].append(dt)
 
         assert r_ct == r_ref, \
@@ -180,11 +187,10 @@ def bench(n=N_FANOUT, *, trials=3, prefix_chars=PREFIX_CHARS):
             "paged radix cache never matched the shared prefix"
         _assert_compile_bounds(eng_ct, "contig")
         _assert_compile_bounds(eng_pg, "paged")
-        for label, eng in (("contig", eng_ct), ("paged", eng_pg)):
-            o0, d0 = marks[label]
-            occ[label].append(max(eng.batch_occupancy[o0:], default=0))
-            decode_ms.setdefault(label, []).extend(
-                eng.decode_step_s[d0:])
+        for label, steps in (("contig", steps_ct), ("paged", steps_pg)):
+            occ[label].append(max((s.attrs["occupancy"] for s in steps),
+                                  default=0))
+            decode_ms.setdefault(label, []).extend(s.dur for s in steps)
 
     med = {m: statistics.median(ts) for m, ts in times.items()}
     peak = {m: max(os) for m, os in occ.items()}

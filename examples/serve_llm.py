@@ -75,11 +75,11 @@ def main():
         summarize_documents(args.docs)
         dt = time.perf_counter() - t0
 
-    occ = engine.batch_occupancy
     print(f"\n{args.docs}+1 LLM calls in {dt:.2f}s — "
           f"{engine.decode_tokens} tokens over {engine.steps} decode steps, "
-          f"mean batch occupancy {sum(occ)/max(len(occ),1):.2f} "
-          f"(max {max(occ, default=0)}): PopPy's parallel calls shared "
+          f"mean batch occupancy "
+          f"{engine.occupancy_sum / max(engine.steps, 1):.2f} "
+          f"(max {engine.max_occupancy}): PopPy's parallel calls shared "
           "decode batches")
     es = engine.stats()
     print(f"prefill: {es['prefill_tokens_computed']} tokens computed, "

@@ -4,14 +4,17 @@ The JSON output loads directly in Perfetto (https://ui.perfetto.dev) or
 ``chrome://tracing``.  Mapping:
 
 * every span becomes a ``ph: "X"`` complete event with microsecond
-  ``ts``/``dur`` relative to the tracer origin;
+  ``ts``/``dur``, ``ts`` relative to the tracer's ``origin`` (so the
+  trace starts at 0; the origin's absolute ``perf_counter`` reading
+  rides in ``metadata.origin_s``);
 * every :class:`~.spans.Span` *track* (effect domain, backend replica,
   decode slot, offload worker) becomes its own thread row via ``tid`` plus
   a ``thread_name`` metadata event, so domains/replicas/slots render as
   separate lanes;
 * span ids and parent links ride in ``args`` (``span_id``/``parent_id``)
   together with the span's attrs, so :func:`load_spans` round-trips a file
-  back into ``Span`` objects for offline ``python -m repro.obs`` analysis.
+  back into ``Span`` objects (on the absolute clock again) for offline
+  ``python -m repro.obs`` analysis.
 """
 
 from __future__ import annotations
@@ -44,6 +47,7 @@ def chrome_trace(tracer: Tracer) -> dict[str, Any]:
     spans = tracer.closed_spans()
     instants = sorted(tracer.instants, key=lambda s: s.t0)
     tids = _track_ids([*spans, *instants])
+    origin = tracer.origin
     events: list[dict[str, Any]] = [
         {"ph": "M", "pid": _PID, "tid": 0, "name": "process_name",
          "args": {"name": tracer.name}},
@@ -58,7 +62,8 @@ def chrome_trace(tracer: Tracer) -> dict[str, Any]:
         events.append({
             "ph": "X", "pid": _PID, "tid": tids[s.track],
             "name": s.name, "cat": s.cat or "span",
-            "ts": round(s.t0 * 1e6, 3), "dur": round(s.dur * 1e6, 3),
+            "ts": round((s.t0 - origin) * 1e6, 3),
+            "dur": round(s.dur * 1e6, 3),
             "args": {"span_id": s.span_id, "parent_id": s.parent_id,
                      **s.attrs},
         })
@@ -66,14 +71,15 @@ def chrome_trace(tracer: Tracer) -> dict[str, Any]:
         events.append({
             "ph": "i", "pid": _PID, "tid": tids[s.track],
             "name": s.name, "cat": s.cat or "event", "s": "t",
-            "ts": round(s.t0 * 1e6, 3),
+            "ts": round((s.t0 - origin) * 1e6, 3),
             "args": {"span_id": s.span_id, "parent_id": s.parent_id,
                      **s.attrs},
         })
     return {
         "traceEvents": events,
         "displayTimeUnit": "ms",
-        "metadata": {"tracer": tracer.name, "epoch_s": tracer.epoch},
+        "metadata": {"tracer": tracer.name, "epoch_s": tracer.epoch,
+                     "origin_s": origin},
     }
 
 
@@ -90,6 +96,7 @@ def load_spans(path: str) -> list[Span]:
     (complete events only — instants carry no duration to attribute)."""
     with open(path) as f:
         doc = json.load(f)
+    origin = doc.get("metadata", {}).get("origin_s", 0.0)
     tracks: dict[int, str] = {}
     for ev in doc.get("traceEvents", []):
         if ev.get("ph") == "M" and ev.get("name") == "thread_name":
@@ -99,7 +106,7 @@ def load_spans(path: str) -> list[Span]:
         if ev.get("ph") != "X":
             continue
         args = dict(ev.get("args", {}))
-        t0 = ev["ts"] / 1e6
+        t0 = origin + ev["ts"] / 1e6
         spans.append(Span(
             name=ev["name"], cat=ev.get("cat", ""),
             t0=t0, t1=t0 + ev.get("dur", 0) / 1e6,

@@ -23,6 +23,11 @@ Design constraints, in order:
    workers, the ai bridge loop, and the engine loop all record
    concurrently.
 
+Times are absolute ``time.perf_counter()`` seconds: the host clock a
+profiler trace is anchored to, so a span can be placed beside the device
+ops of a ``jax.profiler`` trace.  Exporters subtract the tracer's
+``origin`` where they show relative times.
+
 Enable with ``with obs.tracing() as trz:`` or the ``POPPY_TRACE``
 environment variable (``POPPY_TRACE=1`` records; ``POPPY_TRACE=out.json``
 additionally writes a Chrome/Perfetto trace at process exit).
@@ -37,7 +42,7 @@ import os
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, ContextManager, Iterator
+from typing import Any, ContextManager
 
 __all__ = [
     "Span", "Tracer", "tracing", "current_tracer", "current_span",
@@ -58,8 +63,8 @@ PHASE_MIN_S = 100e-6
 
 @dataclass(slots=True)
 class Span:
-    """One recorded interval.  Times are seconds relative to the owning
-    tracer's monotonic origin; ``t1 < 0`` means still open."""
+    """One recorded interval.  Times are absolute ``time.perf_counter()``
+    seconds; ``t1 < 0`` means still open."""
 
     name: str
     cat: str = ""
@@ -95,16 +100,17 @@ def current_span() -> Span | None:
 
 
 class Tracer:
-    """Thread-safe span recorder with a per-tracer monotonic origin.
+    """Thread-safe span recorder.
 
-    All timestamps are relative to ``origin`` (a ``time.monotonic`` value
-    captured at construction); ``epoch`` is the matching wall-clock
-    ``time.time`` so traces from different processes can be aligned.
+    All timestamps are absolute ``time.perf_counter()`` seconds;
+    ``origin`` is the reading at construction (exports start there) and
+    ``epoch`` the matching wall-clock ``time.time``, so traces from
+    different processes can be aligned.
     """
 
     def __init__(self, name: str = "poppy") -> None:
         self.name = name
-        self.origin = time.monotonic()
+        self.origin = time.perf_counter()
         self.epoch = time.time()
         self.spans: list[Span] = []
         self.instants: list[Span] = []
@@ -115,8 +121,8 @@ class Tracer:
         self._next_id = itertools.count(1).__next__
 
     def now(self) -> float:
-        """Seconds since this tracer's origin."""
-        return time.monotonic() - self.origin
+        """The tracer's clock: ``time.perf_counter()`` seconds."""
+        return time.perf_counter()
 
     # -- recording -----------------------------------------------------------
 
@@ -137,7 +143,7 @@ class Tracer:
         if track == "main" and parent is not None:
             track = parent.track    # nest on the parent's display lane
         sp = Span(name=name, cat=cat,
-                  t0=time.monotonic() - self.origin,
+                  t0=time.perf_counter(),
                   span_id=self._next_id(),
                   parent_id=parent.span_id if parent is not None else 0,
                   track=track, attrs=attrs)
@@ -148,7 +154,7 @@ class Tracer:
     def end(self, span: Span, **attrs: Any) -> Span:
         """Close a span (idempotent: the first ``end`` wins)."""
         if span.t1 < 0:
-            span.t1 = time.monotonic() - self.origin
+            span.t1 = time.perf_counter()
         if attrs:
             span.attrs.update(attrs)
         return span
@@ -157,7 +163,8 @@ class Tracer:
                track: str = "main", parent: Span | None = None,
                **attrs: Any) -> Span:
         """Append an already-finished span retroactively: ``t0`` is a
-        tracer-relative start time (from :meth:`now`), the end is *now*.
+        start time on the tracer's clock (from :meth:`now`), the end is
+        *now*.
 
         This is the cheap pattern for *phase* spans that usually take no
         time (argument-dependency waits, lock-chain waits, dynamic
@@ -171,7 +178,7 @@ class Tracer:
         if track == "main" and parent is not None:
             track = parent.track
         sp = Span(name=name, cat=cat, t0=t0,
-                  t1=time.monotonic() - self.origin,
+                  t1=time.perf_counter(),
                   span_id=self._next_id(),
                   parent_id=parent.span_id if parent is not None else 0,
                   track=track, attrs=attrs)
@@ -187,7 +194,7 @@ class Tracer:
             parent = _current_span.get()
         if track == "main" and parent is not None:
             track = parent.track
-        t = time.monotonic() - self.origin
+        t = time.perf_counter()
         sp = Span(name=name, cat=cat, t0=t, t1=t,
                   span_id=self._next_id(),
                   parent_id=parent.span_id if parent is not None else 0,
@@ -331,8 +338,3 @@ def maybe_span(name: str, *, cat: str = "", track: str = "main",
     if t is None:
         return _NULL_CM
     return t.span(name, cat=cat, track=track, parent=parent, **attrs)
-
-
-@contextlib.contextmanager
-def _noop() -> Iterator[None]:  # pragma: no cover - kept for doc symmetry
-    yield None

@@ -37,6 +37,7 @@ step, so duplicates never decode to ``max_new_tokens`` in the dark.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import time
 from dataclasses import dataclass, field
 
@@ -63,6 +64,9 @@ from repro.serving.prefix_cache import (
 )
 from repro.serving.sampler import sample_tokens, sample_tokens_batched
 
+#: What ``ServingEngine._span`` returns in a pass that is not traced.
+_UNTRACED = contextlib.nullcontext()
+
 
 @dataclass
 class Request:
@@ -72,8 +76,10 @@ class Request:
     done: asyncio.Future | None = None
     out_tokens: list = field(default_factory=list)
     slot: int = -1
+    # host times, ``time.perf_counter()`` seconds (the tracer's clock)
     submitted_at: float = 0.0
     started_at: float = 0.0
+    first_token_at: float = 0.0
     finished_at: float = 0.0
     # observability: the client-side request span (and its tracer) — the
     # scheduler loop parents its per-request work (admission, prefill
@@ -270,8 +276,21 @@ class ServingEngine:
         self._stop = False
         self.steps = 0
         self.decode_tokens = 0
-        self.batch_occupancy: list[int] = []
-        self.decode_step_s: list[float] = []
+        # decode-batch occupancy: running max and sum over steps (the
+        # registry, when one is given, also keeps a histogram)
+        self.max_occupancy = 0
+        self.occupancy_sum = 0
+        self._h_occupancy = metrics.histogram("serving_batch_occupancy") \
+            if metrics is not None else None
+        # blocking device->host reads made by the loop (``_host``)
+        self.host_syncs = 0
+        self._c_syncs = metrics.counter("serving_host_syncs") \
+            if metrics is not None else None
+        # tracers of the requests and warm tasks the engine holds: the
+        # loop task's own context predates any ``tracing()`` block, so a
+        # pass records its spans on the newest of these
+        self._tracers: list = []
+        self._pass: _Pass | None = None     # the current traced pass
         self.prefill_shapes: set = set()
         # (prefix tokens, padded length) -> padded prefix KV.  A burst of
         # fan-out requests shares one matched prefix; without this every
@@ -546,7 +565,7 @@ class ServingEngine:
                     f"evicted — it could never be admitted")
         req = Request(prompt_tokens, max_new_tokens, temperature,
                       done=asyncio.get_running_loop().create_future(),
-                      submitted_at=time.monotonic())
+                      submitted_at=time.perf_counter())
         trz = current_tracer()
         if trz is None:
             await self.queue.put(req)
@@ -557,16 +576,21 @@ class ServingEngine:
         # admission → prefill chunks → shared decode steps → finish) from
         # the client's side; scheduler-side spans attach to it by parent
         req.trz = trz
-        with trz.span("request", cat="serving.request",
-                      n_prompt=len(prompt_tokens),
-                      max_new=max_new_tokens) as sp:
-            req.span = sp
-            await self.queue.put(req)
-            self._wake_event().set()
-            self.ensure_running()
-            out = await req.done
-            sp.attrs["n_out"] = len(out)
-            return out
+        self._tracers.append(trz)
+        try:
+            with trz.span("request", cat="serving.request",
+                          n_prompt=len(prompt_tokens),
+                          max_new=max_new_tokens) as sp:
+                req.span = sp
+                await self.queue.put(req)
+                self._wake_event().set()
+                self.ensure_running()
+                out = await req.done
+                sp.attrs["n_out"] = len(out)
+                sp.attrs["first_token_t"] = req.first_token_at
+                return out
+        finally:
+            self._tracers.remove(trz)
 
     def prompt_logits(self, tokens):
         """Logits [1, V] for the token after ``tokens``, through the
@@ -611,6 +635,7 @@ class ServingEngine:
             task.trz = trz
             task.span = trz.begin("warm_prefix", cat="serving.prefix",
                                   tokens=len(tokens))
+            self._tracers.append(trz)
         self._warm_waiting.append(task)
         self._wake_event().set()
         self.ensure_running()
@@ -619,6 +644,7 @@ class ServingEngine:
         finally:
             if task.span is not None:
                 trz.end(task.span)
+                self._tracers.remove(trz)
         return {"tokens": len(tokens), "computed": computed}
 
     def reset_prefix_cache(self):
@@ -719,7 +745,8 @@ class ServingEngine:
         out = {
             "steps": self.steps,
             "decode_tokens": self.decode_tokens,
-            "max_occupancy": max(self.batch_occupancy, default=0),
+            "max_occupancy": self.max_occupancy,
+            "host_syncs": self.host_syncs,
             "prefill_compilations": self.prefill_compilations,
             "prefill_shape_bound": self.prefill_shape_bound,
             "prefill_buckets": list(self._buckets),
@@ -858,21 +885,19 @@ class ServingEngine:
         if self.prefill_chunk:
             chunk = min(chunk, self.prefill_chunk)
         seg = task.tokens[task.covered:task.covered + chunk]
-        trz = task.req.trz if task.req is not None else task.trz
-        psp = None
-        if trz is not None:
-            psp = trz.begin(
-                "prefill.chunk", cat="serving.prefill",
-                parent=(task.req.span if task.req is not None
-                        else task.span),
-                track=self._tr(f"slot:{task.slot}" if task.slot >= 0
-                               else "prefill"),
-                tokens=chunk, covered=task.covered)
-        logits, kvseg = self._run_prefill(
-            seg, task.acc, task.covered,
-            prefix_key=task.tokens[:task.covered])
-        if psp is not None:
-            trz.end(psp)
+        # ends at enqueue: the chunk's device time is in the profiler's
+        # trace (and in the wait of the decode step queued behind it)
+        with self._span("prefill.chunk", "serving.prefill",
+                        f"slot:{task.slot}" if task.slot >= 0
+                        else "prefill",
+                        new=chunk, cached=task.covered) as psp:
+            if psp is not None:
+                owner = task.req.span if task.req is not None \
+                    else task.span
+                psp.attrs["request"] = owner.span_id if owner else 0
+            logits, kvseg = self._run_prefill(
+                seg, task.acc, task.covered,
+                prefix_key=task.tokens[:task.covered])
         task.acc = kvseg if task.acc is None \
             else tree_concat([task.acc, kvseg], self._seq_axes)
         task.covered += chunk
@@ -950,7 +975,8 @@ class ServingEngine:
 
     def _begin_decode(self, req: Request, slot: int, logits):
         tok = self._sample(logits, req)
-        req.out_tokens.append(int(tok[0]))
+        req.out_tokens.append(int(self._host(tok)[0]))
+        req.first_token_at = time.perf_counter()
         self.cur_tokens = self.cur_tokens.at[slot, 0].set(tok[0])
         self.positions = self.positions.at[slot].set(len(req.prompt_tokens))
         self.live[slot] = True
@@ -989,7 +1015,7 @@ class ServingEngine:
             req = self.queue.get_nowait()
             if req.abandoned:  # cancelled while queued
                 continue
-            req.started_at = time.monotonic()
+            req.started_at = time.perf_counter()
             slot = self.free_slots.pop()
             req.slot = slot
             self._note_admit(req, slot)
@@ -1025,7 +1051,7 @@ class ServingEngine:
             if task is None:
                 self._wait_pages.insert(0, req)
                 return
-            req.started_at = time.monotonic()
+            req.started_at = time.perf_counter()
             self._note_admit(req, task.slot)
             self._pending.append(task)
 
@@ -1137,7 +1163,7 @@ class ServingEngine:
 
     def _finish(self, slot):
         req = self.active.pop(slot)
-        req.finished_at = time.monotonic()
+        req.finished_at = time.perf_counter()
         self.live[slot] = False
         if self.paged_kv:
             self._free_slot_paged(slot)
@@ -1154,71 +1180,128 @@ class ServingEngine:
                     or len(req.out_tokens) >= req.max_new_tokens
                     or (self.eos_token is not None
                         and last == self.eos_token)
-                    or int(self.positions[slot]) >= self.max_len - 1):
+                    or int(self._host(self.positions[slot]))
+                    >= self.max_len - 1):
                 self._finish(slot)
 
+    def _host(self, x) -> np.ndarray:
+        """Blocking device->host read of ``x`` (a writable copy).  Every
+        such read of the loop goes through here and is counted
+        (``host_syncs``, the ``syncs`` of the pass's ``loop.iter``)."""
+        self.host_syncs += 1
+        if self._c_syncs is not None:
+            self._c_syncs.inc()
+        return np.array(x)
+
     def _decode_once(self):
-        # decode steps serve the whole batch: record them detached on the
-        # engine's decode track (not under any one request), on whichever
-        # tracer the active requests carry
-        trz = next((r.trz for r in self.active.values()
-                    if r.trz is not None), None)
-        dsp = trz.begin("decode.step", cat="serving.decode",
-                        parent=DETACHED, track=self._tr("decode"),
-                        occupancy=len(self.active)) \
-            if trz is not None else None
-        t0 = time.perf_counter()
-        if self.paged_kv:
-            if self._table_dirty:
-                self._table_dev = jnp.asarray(self._page_table)
-                self._table_dirty = False
-            logits, self.kv_pages = self._decode_paged(
-                self.params, self.kv_pages, self.cur_tokens,
-                self.positions, self._table_dev)
-        else:
-            logits, self.cache = self._decode(
-                self.params, self.cache, self.cur_tokens, self.positions)
-        self.steps += 1
-        self.batch_occupancy.append(len(self.active))
-        stochastic = any(r.temperature > 0.0 for r in self.active.values())
-        if stochastic:
-            # one RNG split + one device call + one host transfer for the
-            # whole batch, however many slots sample
-            self._rng, k = jax.random.split(self._rng)
-            temps = np.zeros((self.max_slots,), np.float32)
+        with self._span("decode.step", "serving.decode", "decode",
+                        occupancy=len(self.active)) as dsp:
+            if dsp is not None:
+                dsp.attrs["slots"] = sorted(self.active)
+            if self.paged_kv:
+                if self._table_dirty:
+                    self._table_dev = jnp.asarray(self._page_table)
+                    self._table_dirty = False
+                logits, self.kv_pages = self._decode_paged(
+                    self.params, self.kv_pages, self.cur_tokens,
+                    self.positions, self._table_dev)
+            else:
+                logits, self.cache = self._decode(
+                    self.params, self.cache, self.cur_tokens,
+                    self.positions)
+            n = len(self.active)
+            self.steps += 1
+            self.occupancy_sum += n
+            self.max_occupancy = max(self.max_occupancy, n)
+            if self._h_occupancy is not None:
+                self._h_occupancy.observe(n)
+            stochastic = any(r.temperature > 0.0
+                             for r in self.active.values())
+            if stochastic:
+                # one RNG split + one device call + one host transfer for
+                # the whole batch, however many slots sample
+                self._rng, k = jax.random.split(self._rng)
+                temps = np.zeros((self.max_slots,), np.float32)
+                for slot, req in self.active.items():
+                    temps[slot] = req.temperature
+                toks = self._sample_all(k, logits, jnp.asarray(temps))
+            else:
+                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            # the step's one wait for the device; it also waits for the
+            # prefill chunks enqueued ahead of the step in this pass
+            with self._span("device.wait", "serving.decode", "decode",
+                            parent=dsp) as wsp:
+                if wsp is not None:
+                    wsp.attrs["prefill_queued"] = \
+                        self.prefill_chunks - self._pass.chunks0
+                nxt = self._host(toks)
+            new_cur = self._host(self.cur_tokens)
+            new_pos = self._host(self.positions)
             for slot, req in self.active.items():
-                temps[slot] = req.temperature
-            toks = self._sample_all(k, logits, jnp.asarray(temps))
-        else:
-            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        nxt = np.asarray(toks)                # host sync: step really done
-        self.decode_step_s.append(time.perf_counter() - t0)
-        new_cur = np.array(self.cur_tokens)   # writable copies
-        new_pos = np.array(self.positions)
-        for slot, req in self.active.items():
-            tok = int(nxt[slot])
-            req.out_tokens.append(tok)
-            self.decode_tokens += 1
-            new_cur[slot, 0] = tok
-            new_pos[slot] += 1
-        self.cur_tokens = jnp.asarray(new_cur)
-        self.positions = jnp.asarray(new_pos)
-        if dsp is not None:
-            trz.end(dsp)
+                tok = int(nxt[slot])
+                req.out_tokens.append(tok)
+                self.decode_tokens += 1
+                new_cur[slot, 0] = tok
+                new_pos[slot] += 1
+            self.cur_tokens = jnp.asarray(new_cur)
+            self.positions = jnp.asarray(new_pos)
+
+    # -- the loop -------------------------------------------------------------
+
+    def _span(self, name: str, cat: str, track: str, *, parent=None,
+              **attrs):
+        """A span of the current pass of the loop (a child of its
+        ``loop.iter`` unless ``parent`` is given), or the shared null
+        context when the pass is not traced."""
+        p = self._pass
+        if p is None:
+            return _UNTRACED
+        return _loop_span(p.trz, name, cat,
+                          p.span if parent is None else parent,
+                          self._tr(track), attrs)
+
+    def _run_pass(self) -> bool:
+        """One pass of the scheduler: admit, one prefill chunk, one
+        decode step for the whole batch, retire.  Returns whether it did
+        any work."""
+        with self._span("queue.drain", "serving.loop", "engine"):
+            self._drain_queue()
+        progressed = False
+        if self._pending:
+            # one prefill chunk between decode steps: a long admit
+            # yields to the live batch instead of freezing it
+            self._prefill_step()
+            progressed = True
+        if self.active:
+            self._decode_once()
+            with self._span("retire", "serving.loop", "engine"):
+                self._retire_finished()
+            progressed = True
+        return progressed
+
+    def _run_traced_pass(self, trz) -> bool:
+        """``_run_pass`` inside a ``loop.iter`` span: detached, on the
+        engine's track, ending with the requests still ``live``, whether
+        it ``decoded`` and the host ``syncs`` it made."""
+        syncs, steps = self.host_syncs, self.steps
+        with _loop_span(trz, "loop.iter", "serving.loop", DETACHED,
+                        self._tr("engine"), {}) as sp:
+            self._pass = _Pass(trz, sp, self.prefill_chunks)
+            try:
+                progressed = self._run_pass()
+            finally:
+                self._pass = None
+            sp.attrs.update(live=len(self.active),
+                            decoded=self.steps - steps,
+                            syncs=self.host_syncs - syncs)
+        return progressed
 
     async def _loop(self):
         while not self._stop:
-            self._drain_queue()
-            progressed = False
-            if self._pending:
-                # one prefill chunk between decode steps: a long admit
-                # yields to the live batch instead of freezing it
-                self._prefill_step()
-                progressed = True
-            if self.active:
-                self._decode_once()
-                self._retire_finished()
-                progressed = True
+            if self._tracers:
+                progressed = self._run_traced_pass(self._tracers[-1])
+            else:
+                progressed = self._run_pass()
             if progressed:
                 await asyncio.sleep(self.step_sleep or 0)
                 continue
@@ -1238,6 +1321,30 @@ class ServingEngine:
                 if self.queue.empty() and not self._warm_waiting \
                         and not self._pending and not self._wait_pages:
                     return
+
+
+@dataclass
+class _Pass:
+    """A traced pass of the loop: its tracer, its ``loop.iter`` span,
+    and the prefill chunks enqueued before it began."""
+
+    trz: object
+    span: object
+    chunks0: int
+
+
+@contextlib.contextmanager
+def _loop_span(trz, name, cat, parent, track, attrs):
+    """One span of the engine's loop.  While a ``jax.profiler`` session
+    records, it also enters a ``TraceAnnotation`` of the same name, so a
+    profile shows the loop on the profiler's own clock beside the device
+    ops."""
+    with trz.span(name, cat=cat, track=track, parent=parent, **attrs) as sp:
+        if jax.profiler.TraceAnnotation.is_enabled():
+            with jax.profiler.TraceAnnotation(name):
+                yield sp
+        else:
+            yield sp
 
 
 def _write_slot_cache(full, new, slot):

@@ -77,6 +77,22 @@ def test_record_retroactive_phase_spans():
     assert names.count("lock.wait") == 1
 
 
+def test_times_are_perf_counter_and_exports_start_at_zero():
+    # spans sit on the host clock a profiler trace is anchored to; an
+    # export subtracts the tracer's origin, so Perfetto still starts at 0
+    trz = Tracer()
+    t = time.perf_counter()
+    with trz.span("a") as sp:
+        pass
+    ev = trz.event("mark")
+    assert abs(sp.t0 - t) < 1e-3 and abs(ev.t0 - time.perf_counter()) < 1e-3
+    assert trz.origin <= sp.t0 and abs(trz.now() - time.perf_counter()) < 1e-3
+    ts = [e["ts"] for e in obs.chrome_trace(trz)["traceEvents"]
+          if e["ph"] in ("X", "i")]
+    assert len(ts) == 2 and 0 <= min(ts) < 1e3       # microseconds
+    assert obs.render_timeline([sp]).startswith("timeline:")
+
+
 def test_event_instants_are_separate_from_spans():
     trz = Tracer()
     with trz.span("outer") as outer:

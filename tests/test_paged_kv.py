@@ -8,8 +8,11 @@ import jax
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import get_config
 from repro.models import build_model
+from repro.obs import spans as spans_mod
+from repro.obs.metrics import MetricsRegistry
 from repro.serving.engine import PageAllocator, ServingEngine
 from repro.serving.prefix_cache import PagedPrefixCache
 
@@ -291,10 +294,9 @@ def test_unsupported_models_fall_back_to_contiguous(served):
 
 
 def test_paged_decode_timing_and_gauges(served):
-    """Observability rides along: decode step timings accumulate and the
+    """Observability rides along: decode steps are counted and the
     metrics registry carries the page gauges/counters."""
     cfg, model, params = served
-    from repro.obs.metrics import MetricsRegistry
     reg = MetricsRegistry()
     engine = ServingEngine(model, params, max_slots=2, max_len=64,
                            page_size=16, metrics=reg)
@@ -306,8 +308,84 @@ def test_paged_decode_timing_and_gauges(served):
 
     out = asyncio.run(go())
     assert len(out) == 4
-    assert len(engine.decode_step_s) >= 3
+    assert engine.steps >= 3
     snap = reg.snapshot()
     assert "serving_pages_free" in snap
     free = engine.allocator.free_count
     assert snap["serving_pages_free"]["value"] == free
+
+
+def test_traced_loop_counts_every_host_sync(served):
+    """A traced run records the loop's passes: every ``decode.step`` lies
+    inside a ``loop.iter`` with a ``device.wait`` child, and a pass's
+    ``syncs`` are the reads it made: 3 for a decode step, one per live
+    slot whose stop check reaches the position read, one per first token
+    sampled.  Their sum is ``host_syncs``; ``first_token_t`` lies inside
+    its request span."""
+    cfg, model, params = served
+    reg = MetricsRegistry()
+    engine = ServingEngine(model, params, max_slots=4, max_len=64,
+                           page_size=16, prefill_chunk=16, metrics=reg)
+    prompts = [[3, 1, 4, 1, 5], list(range(1, 40)), [9, 8, 7]]
+    max_new = [3, 5, 7]
+
+    async def go():
+        outs = await asyncio.gather(*[
+            engine.generate(p, max_new_tokens=m)
+            for p, m in zip(prompts, max_new)])
+        await engine.stop()
+        return outs
+
+    with obs.tracing() as trz:
+        outs = asyncio.run(go())
+    assert [len(o) for o in outs] == max_new
+    spans = trz.closed_spans()
+    iters = [s for s in spans if s.name == "loop.iter"]
+    reqs = [s for s in spans if s.name == "request"]
+    steps = {s.parent_id: s for s in spans if s.name == "decode.step"}
+    assert len(steps) == engine.steps >= max(max_new) - 1
+    by_id = {s.span_id: s for s in iters}
+    for it_id, st in steps.items():
+        it = by_id[it_id]
+        assert it.t0 <= st.t0 and st.t1 <= it.t1
+        assert [s.name for s in spans if s.parent_id == st.span_id] \
+            == ["device.wait"]
+    for r in reqs:
+        assert r.t0 < r.attrs["first_token_t"] < r.t1
+    out_len = {}            # slot -> (its request span, tokens so far)
+    for it in iters:
+        firsts = [r for r in reqs
+                  if it.t0 <= r.attrs["first_token_t"] <= it.t1]
+        for r in firsts:
+            out_len[r.attrs["slot"]] = (r, 1)
+        expected = len(firsts)
+        assert it.attrs["decoded"] == (it.span_id in steps)
+        if it.attrs["decoded"]:
+            expected += 3
+            for slot in steps[it.span_id].attrs["slots"]:
+                r, n = out_len[slot]
+                out_len[slot] = (r, n + 1)
+                expected += n + 1 < r.attrs["max_new"]
+        assert it.attrs["syncs"] == expected
+    total = sum(it.attrs["syncs"] for it in iters)
+    assert engine.stats()["host_syncs"] == total > 0
+    assert reg.snapshot()["serving_host_syncs"] == total
+    occ = [s.attrs["occupancy"] for s in steps.values()]
+    assert engine.stats()["max_occupancy"] == max(occ) >= 2
+    assert engine.occupancy_sum == sum(occ)
+    assert reg.snapshot()["serving_batch_occupancy"]["count"] == len(occ)
+
+
+def test_untraced_generate_allocates_no_spans(served):
+    cfg, model, params = served
+    engine = ServingEngine(model, params, max_slots=2, max_len=64)
+
+    async def go():
+        out = await engine.generate([3, 1, 4, 1, 5], max_new_tokens=4)
+        await engine.stop()
+        return out
+
+    before = spans_mod.SPAN_ALLOCS
+    assert len(asyncio.run(go())) == 4
+    assert spans_mod.SPAN_ALLOCS == before
+    assert engine.stats()["host_syncs"] > 0

@@ -61,7 +61,7 @@ def test_concurrent_requests_match_isolated(served):
         ref = greedy_reference(model, params, p, 4)
         assert o == ref, f"prompt {p}: batched {o} != isolated {ref}"
     # requests overlapped: some decode steps served >1 sequence
-    assert max(engine.batch_occupancy) >= 2
+    assert engine.max_occupancy >= 2
 
 
 def test_more_requests_than_slots(served):
@@ -104,7 +104,7 @@ def test_engine_backed_llm_through_poppy(served):
     # untrained model → arbitrary ids; specials (≥256) decode to ""
     assert all(isinstance(o, str) for o in outs)
     assert engine.decode_tokens > 0
-    assert max(engine.batch_occupancy) >= 2, \
+    assert engine.max_occupancy >= 2, \
         "parallel PopPy calls did not share decode batches"
 
 
@@ -139,16 +139,17 @@ def test_engine_backed_llm_autobatched(served):
     ref, _ = run(False)
     outs, engine = run(True)
     assert outs == ref
-    assert max(engine.batch_occupancy) >= 2, \
+    assert engine.max_occupancy >= 2, \
         "batched PopPy calls did not share decode batches"
 
 
 def test_traced_serving_spans(served):
     """Span tracing across the serving engine (DESIGN.md §4): each request
     gets a ``serving.request`` span carrying slot/queue attrs, prefill
-    chunks parent under their request on the slot's lane, decode steps
-    record detached on the shared ``decode`` track with batch occupancy,
-    and admissions land as instant events."""
+    chunks record under the loop pass that ran them on the slot's lane,
+    naming their request, decode steps record under their pass on the
+    shared ``decode`` track with batch occupancy, and admissions land as
+    instant events."""
     from repro import obs
 
     cfg, model, params = served
@@ -174,17 +175,20 @@ def test_traced_serving_spans(served):
         assert sp.attrs["n_out"] == 4
         assert "slot" in sp.attrs and "queue_s" in sp.attrs
     req_ids = {s.span_id for s in reqs}
+    iters = {s.span_id: s for s in spans if s.name == "loop.iter"}
+    assert all(s.parent_id == 0 for s in iters.values())
     prefills = [s for s in spans if s.cat == "serving.prefill"]
     assert prefills, "no prefill.chunk spans recorded"
     for sp in prefills:
-        assert sp.parent_id in req_ids
+        assert sp.parent_id in iters and sp.attrs["request"] in req_ids
         assert sp.track.startswith("slot:")
-        assert sp.attrs["tokens"] <= 2      # chunked at prefill_chunk
-    decodes = [s for s in spans if s.cat == "serving.decode"]
+        assert sp.attrs["new"] <= 2      # chunked at prefill_chunk
+    decodes = [s for s in spans if s.name == "decode.step"]
     assert decodes, "no decode.step spans recorded"
     for sp in decodes:
-        # decode steps serve the whole batch: detached, on one track
-        assert sp.parent_id == 0 and sp.track == "decode"
+        # decode steps serve the whole batch: under the loop's pass (not
+        # under any one request), on one track
+        assert sp.parent_id in iters and sp.track == "decode"
     assert max(sp.attrs["occupancy"] for sp in decodes) >= 2
     admits = [e for e in trz.instants if e.cat == "serving.admit"]
     assert len(admits) == len(prompts)

@@ -47,7 +47,7 @@ def test_end_to_end_poppy_over_serving_engine():
                     out = pipeline(3)
             else:
                 out = pipeline(3)
-        occupancy = max(engine.batch_occupancy, default=0)
+        occupancy = engine.max_occupancy
         return out, list(log), tr, occupancy
 
     out_plain, log_plain, tr_plain, _ = run("plain")
