@@ -323,6 +323,13 @@ class ServingEngine:
             else (kv_layout or "paged")
         self.paged_kv = self.kv_layout == "paged"
 
+        # each slot's position and current token: the host holds them
+        # (a slot's position is a fact of its request) and uploads them to
+        # the device arrays the decode step reads, so the loop never reads
+        # them back; ``_slots_dirty`` marks an upload owed before a step
+        self._pos_host = np.zeros((max_slots,), np.int32)
+        self._cur_host = np.zeros((max_slots, 1), np.int32)
+        self._slots_dirty = False
         self.positions = jnp.zeros((max_slots,), jnp.int32)
         self.cur_tokens = jnp.zeros((max_slots, 1), jnp.int32)
         self.live = np.zeros((max_slots,), bool)
@@ -974,11 +981,12 @@ class ServingEngine:
         self._begin_decode(req, task.slot, task.last_logits)
 
     def _begin_decode(self, req: Request, slot: int, logits):
-        tok = self._sample(logits, req)
-        req.out_tokens.append(int(self._host(tok)[0]))
+        tok = int(self._host(self._sample(logits, req))[0])
+        req.out_tokens.append(tok)
         req.first_token_at = time.perf_counter()
-        self.cur_tokens = self.cur_tokens.at[slot, 0].set(tok[0])
-        self.positions = self.positions.at[slot].set(len(req.prompt_tokens))
+        self._cur_host[slot, 0] = tok
+        self._pos_host[slot] = len(req.prompt_tokens)
+        self._slots_dirty = True
         self.live[slot] = True
         self.active[slot] = req
 
@@ -1180,8 +1188,7 @@ class ServingEngine:
                     or len(req.out_tokens) >= req.max_new_tokens
                     or (self.eos_token is not None
                         and last == self.eos_token)
-                    or int(self._host(self.positions[slot]))
-                    >= self.max_len - 1):
+                    or self._pos_host[slot] >= self.max_len - 1):
                 self._finish(slot)
 
     def _host(self, x) -> np.ndarray:
@@ -1193,11 +1200,22 @@ class ServingEngine:
             self._c_syncs.inc()
         return np.array(x)
 
+    def _upload_slots(self):
+        """Upload the host's positions and current tokens to the device
+        arrays the decode step reads.  ``jnp.array`` copies: on the CPU a
+        zero-copy ``asarray`` would alias the buffers the host goes on
+        writing."""
+        self.positions = jnp.array(self._pos_host)
+        self.cur_tokens = jnp.array(self._cur_host)
+        self._slots_dirty = False
+
     def _decode_once(self):
         with self._span("decode.step", "serving.decode", "decode",
                         occupancy=len(self.active)) as dsp:
             if dsp is not None:
                 dsp.attrs["slots"] = sorted(self.active)
+            if self._slots_dirty:
+                self._upload_slots()
             if self.paged_kv:
                 if self._table_dirty:
                     self._table_dev = jnp.asarray(self._page_table)
@@ -1235,16 +1253,13 @@ class ServingEngine:
                     wsp.attrs["prefill_queued"] = \
                         self.prefill_chunks - self._pass.chunks0
                 nxt = self._host(toks)
-            new_cur = self._host(self.cur_tokens)
-            new_pos = self._host(self.positions)
             for slot, req in self.active.items():
                 tok = int(nxt[slot])
                 req.out_tokens.append(tok)
                 self.decode_tokens += 1
-                new_cur[slot, 0] = tok
-                new_pos[slot] += 1
-            self.cur_tokens = jnp.asarray(new_cur)
-            self.positions = jnp.asarray(new_pos)
+                self._cur_host[slot, 0] = tok
+                self._pos_host[slot] += 1
+            self._upload_slots()
 
     # -- the loop -------------------------------------------------------------
 

@@ -6,6 +6,7 @@ import asyncio
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -193,6 +194,72 @@ def test_traced_serving_spans(served):
     admits = [e for e in trz.instants if e.cat == "serving.admit"]
     assert len(admits) == len(prompts)
     assert {e.parent_id for e in admits} <= req_ids
+
+
+def _check_slot_mirrors(engine):
+    """After every decode step, assert that the host's positions and
+    current tokens equal the device arrays the next step reads, for every
+    live slot (the test reads the device; the engine does not)."""
+    step = engine._decode_once
+
+    def checked():
+        step()
+        live = sorted(engine.active)
+        assert (engine._pos_host[live]
+                == np.array(engine.positions)[live]).all()
+        assert (engine._cur_host[live]
+                == np.array(engine.cur_tokens)[live]).all()
+
+    engine._decode_once = checked
+
+
+@pytest.mark.parametrize("kv_layout", ["paged", "contiguous"])
+def test_position_stop_reads_no_device_state(served, kv_layout):
+    """A request whose prompt plus ``max_new_tokens`` runs past
+    ``max_len`` retires at position ``max_len - 1`` with the isolated
+    greedy tokens, and the stop check reads nothing from the device: the
+    engine's only reads are one per decode step and one per first
+    token."""
+    cfg, model, params = served
+    engine = ServingEngine(model, params, max_slots=2, max_len=32,
+                           kv_layout=kv_layout)
+    _check_slot_mirrors(engine)
+    prompt = [(7 * i + 3) % 50 + 1 for i in range(24)]
+
+    async def go():
+        out = await engine.generate(prompt, max_new_tokens=40)
+        await engine.stop()
+        return out
+
+    out = asyncio.run(go())
+    assert len(out) == 32 - len(prompt)
+    assert out == greedy_reference(model, params, prompt, len(out))
+    assert engine.host_syncs == engine.steps + 1
+
+
+@pytest.mark.parametrize("kv_layout", ["paged", "contiguous"])
+def test_reused_slots_keep_their_own_tokens(served, kv_layout):
+    """Two slots, five requests that retire at different steps: queued
+    requests take the freed slots mid-batch, and each still gets the
+    tokens it gets when decoded alone."""
+    cfg, model, params = served
+    engine = ServingEngine(model, params, max_slots=2, max_len=64,
+                           kv_layout=kv_layout)
+    _check_slot_mirrors(engine)
+    work = [([1, 2, 3], 2), ([9, 8, 7, 6], 6), ([42, 5], 3),
+            ([3, 1, 4, 1], 5), ([7, 7], 4)]
+
+    async def go():
+        outs = await asyncio.gather(*[
+            engine.generate(p, max_new_tokens=n) for p, n in work])
+        await engine.stop()
+        return outs
+
+    outs = asyncio.run(go())
+    for (p, n), o in zip(work, outs):
+        assert o == greedy_reference(model, params, p, n), p
+    assert engine.max_occupancy == 2
+    assert engine.host_syncs == engine.steps + len(work)
 
 
 @pytest.mark.parametrize("kv_layout", ["paged", "contiguous"])
