@@ -41,7 +41,8 @@ def main():
 
     jax.config.update("jax_enable_compilation_cache", False)
     conf = spec.load_config(args.config)
-    cfg = spec.model_config(conf)
+    cfg = spec.model_config(
+        conf, spec.load_family(conf).from_config(conf))
     eng = conf["engine"]
     model = build_model(cfg)
     topo = topologies.get_topology_desc(platform="tpu",

@@ -4,8 +4,10 @@
         --trace <0|1>
 
 The cell (``BENCHMARK.json``) names a configuration and a traffic mix;
-both are files found by name (``bench/spec.py``), and so is the builder
-of the serving stack that the configuration names.  The run:
+both are files found by name (``bench/spec.py``), and so are the model
+family (``bench/families/<family>.py``: sizes, counts, weights' layout,
+reference) and the builder of the serving stack that the configuration
+names.  The run:
 
 1. set-up: checks that JAX sees a TPU with the chips the cell asks for
    (otherwise it exits 2 and prints no result), makes the weights from
@@ -19,7 +21,8 @@ of the serving stack that the configuration names.  The run:
    due time on the open-loop schedule; programs due in the window are
    then drained;
 3. the check: a sample of the finished calls is scored against the
-   float32 reference (``bench/reference.py``), and every program's
+   float32 reference of the configuration's family (its shape's
+   ``logits_at``, scored by ``bench/reference.py``), and every program's
    result against PopPy's sequential semantics given the same answers;
 4. the result: earlier lines say what was measured; the last line of
    standard output is one JSON object (``correct``, ``attempted``,
@@ -58,7 +61,6 @@ for _p in (str(ROOT), str(ROOT / "src")):
         sys.path.insert(0, _p)
 
 from bench import spec, traffic  # noqa: E402
-from bench.cost import Shape  # noqa: E402
 
 CACHE_DIR = ROOT / ".jax_cache"   # fixed, inside the checkout
 DRAIN_S = 60.0                   # how long past the close a due program may take
@@ -117,7 +119,7 @@ class Run:
     """Everything a metric reader may read (``bench/metrics/*.py``)."""
 
     cell: dict
-    shape: Shape
+    shape: object                     # the family's Shape (bench/families)
     seconds: float
     setup_s: float = 0.0              # process start to the window's open
     t0: float = 0.0                   # window opens (host perf_counter)
@@ -180,12 +182,12 @@ def use_cache():
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
-def build(conf: dict, shape: Shape, seed: int, strict: bool):
+def build(conf: dict, shape, seed: int, strict: bool):
     import jax
 
     from bench import weights
     from repro.models import build_model
-    cfg = spec.model_config(conf, strict=strict)
+    cfg = spec.model_config(conf, shape, strict=strict)
     model = build_model(cfg)
     params = jax.block_until_ready(
         weights.program_params(shape, seed, model))
@@ -543,7 +545,7 @@ def run_cell(bm: dict, w: dict, seed: int, seconds: float, trace: bool, *,
     meter = CompileMeter(jax)
     conf = spec.load_config(w["config"], bm, root)
     mix = {**spec.load_traffic(w["traffic"], root), **(mix_override or {})}
-    shape = Shape.from_config(conf)
+    shape = spec.load_family(conf, root).from_config(conf)
     log("device", {"platform": dev.platform, "kind": dev.device_kind,
                    "count": len(devices), "jax": jax.__version__})
     c0 = meter.snapshot()

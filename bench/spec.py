@@ -12,18 +12,26 @@ each per-layer metric.  Each part is a file of its own under ``bench/``:
   ``"backend"`` (default ``local``): a builder with ``build(model,
   params, conf, tokenizer, devices)`` that puts the serving stack
   together (one engine, a fleet of replicas, ...);
+* ``bench/families/<family>.py``, named by the configuration's
+  ``"family"`` (default ``dense``): the model family's ``Shape``, which
+  holds the sizes and carries the architecture's mathematics (its FLOP
+  and byte counts, its weights' leaves and layout, its float32
+  reference); a family that differs from another in one step subclasses
+  that family's ``Shape`` and overrides the step;
 * ``bench/metrics/<metric>.py``, for every metric, end-to-end and per
   layer: a reader with ``read(run) -> float | None`` (``None``: nothing
   to read in this run, the metric is left out).
 
-A later cell is a new ``workloads`` entry plus new files; no file here
-changes.
+A later cell is a new ``workloads`` entry plus new files, also where it
+brings a new architecture (a new family file) or serving stack; no file
+here changes.
 """
 
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
@@ -79,10 +87,16 @@ def backend_path(conf: dict, root: Path = ROOT) -> Path:
             / f"{conf.get('backend', 'local')}.py")
 
 
+def family_path(conf: dict, root: Path = ROOT) -> Path:
+    return (Path(root) / "bench" / "families"
+            / f"{conf.get('family', 'dense')}.py")
+
+
 def _module(path: Path, name: str):
     mod_spec = importlib.util.spec_from_file_location(
         name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(mod_spec)
+    sys.modules[mod_spec.name] = mod     # dataclasses look their module up
     mod_spec.loader.exec_module(mod)
     return mod
 
@@ -96,6 +110,12 @@ def load_builder(conf: dict, root: Path = ROOT):
     """The ``build`` function of the configuration's backend file."""
     path = backend_path(conf, root)
     return _module(path, f"bench_backend_{path.stem}").build
+
+
+def load_family(conf: dict, root: Path = ROOT):
+    """The ``Shape`` class of the configuration's family file."""
+    path = family_path(conf, root)
+    return _module(path, f"bench_family_{path.stem}").Shape
 
 
 def cell_metrics(bm: dict, name: str) -> tuple[list, list]:
@@ -125,6 +145,9 @@ def validate(bm: dict, root: Path = ROOT) -> list[str]:
             if not backend_path(conf, root).is_file():
                 faults.append(f"{w['name']}: no backend file for "
                               f"{conf.get('backend', 'local')!r}")
+            if not family_path(conf, root).is_file():
+                faults.append(f"{w['name']}: no family file for "
+                              f"{conf.get('family', 'dense')!r}")
             if traffic_path(w["traffic"], root).is_file():
                 faults += [f"{w['name']}: {f}" for f in _mix_faults(
                     load_traffic(w["traffic"], root), conf,
@@ -171,22 +194,17 @@ def _mix_faults(mix: dict, conf: dict, seconds: float) -> list[str]:
     return []
 
 
-def model_config(conf: dict, *, strict: bool = True):
+def model_config(conf: dict, shape, *, strict: bool = True):
     """The program's ``ModelConfig`` for a configuration file: the
-    registry model at the file's sizes.  With ``strict`` (every chip run)
-    a width that differs from the registry's is an error, never a silent
-    change; tests turn it off to run the same model at toy widths."""
+    registry model at the sizes of ``shape``, the file's shape in its
+    family (``load_family``), which names the fields it fixes.  With
+    ``strict`` (every chip run) a width that differs from the registry's
+    is an error, never a silent change; tests turn it off to run the
+    same model at toy widths."""
     from repro.configs import get_config
 
     cfg = get_config(conf["registry"])
-    want = {"d_model": conf["hidden_size"], "d_ff": conf["intermediate_size"],
-            "num_heads": conf["num_attention_heads"],
-            "num_kv_heads": conf["num_key_value_heads"],
-            "head_dim": conf["hidden_size"] // conf["num_attention_heads"],
-            "vocab_size": conf["vocab_size"],
-            "rope_theta": float(conf["rope_theta"]),
-            "norm_type": conf["norm"],
-            "tie_embeddings": conf["tie_word_embeddings"]}
+    want = shape.registry_fields(conf)
     got = {k: getattr(cfg, k) for k in want}
     if strict and got != want:
         raise ValueError(f"registry {conf['registry']!r} is not the "

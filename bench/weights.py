@@ -8,10 +8,11 @@ deterministic step.  So the served weights do not depend on how XLA fuses
 the generator, and the reference, which makes each layer alone after the
 program has been freed, reads exactly the weights that were served.
 
-Leaves are kept in a plain layout (``q`` is ``[d_model, heads *
-head_dim]``, heads major; ``down`` is ``[d_ff, d_model]``).
+Which leaves there are, their sizes and scales, and how the program
+lays them out are the family's (``bench/families/<family>.py``: the
+shape's ``layer_leaves``, ``global_leaves`` and ``to_program``).
 :func:`program_params` makes all of them on the device in one jitted call
-and lays them out as ``repro.models`` expects.
+and checks the layout against the model's own.
 """
 
 from __future__ import annotations
@@ -20,8 +21,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-
-from bench.cost import Shape
 
 TRI_STD = 65535 / math.sqrt(6)     # std of u1 + u2 - 65535, u uniform 16-bit
 
@@ -43,78 +42,23 @@ def _tri(key, shape, std: float, offset: float = 0.0):
     return (offset + n.astype(jnp.float32) * scale).astype(jnp.bfloat16)
 
 
-def layer_leaves(s: Shape) -> dict:
-    """name -> (shape, std, offset) of one layer's leaves."""
-    D, F = s.d_model, s.d_ff
-    q, kv = s.heads * s.head_dim, s.kv_heads * s.head_dim
-    out = {"q": ((D, q), D ** -0.5, 0.0), "k": ((D, kv), D ** -0.5, 0.0),
-           "v": ((D, kv), D ** -0.5, 0.0), "o": ((q, D), q ** -0.5, 0.0),
-           "gate": ((D, F), D ** -0.5, 0.0), "up": ((D, F), D ** -0.5, 0.0),
-           "down": ((F, D), F ** -0.5, 0.0)}
-    out.update(_norm_leaves(s, "ln1"))
-    out.update(_norm_leaves(s, "ln2"))
-    return out
-
-
-def _norm_leaves(s: Shape, name: str) -> dict:
-    # layernorm: a weight near 1 and a bias; rmsnorm: the weight's offset
-    # from 1 (the program and the reference both multiply by 1 + w)
-    if s.norm == "layernorm":
-        return {f"{name}_w": ((s.d_model,), 0.1, 1.0),
-                f"{name}_b": ((s.d_model,), 0.1, 0.0)}
-    return {f"{name}_w": ((s.d_model,), 0.1, 0.0)}
-
-
-def global_leaves(s: Shape) -> dict:
-    out = {"embed": ((s.vocab, s.d_model), 1.0, 0.0),
-           "head": ((s.d_model, s.vocab), s.d_model ** -0.5, 0.0)}
-    out.update(_norm_leaves(s, "final"))
-    return out
-
-
 def _make(key, leaves: dict) -> dict:
     return {name: _tri(jax.random.fold_in(key, i), shape, std, off)
             for i, (name, (shape, std, off)) in enumerate(sorted(
                 leaves.items()))}
 
 
-def layer_weights(s: Shape, key, layer):
-    """One layer's leaves, bf16 (``layer`` may be traced)."""
-    return _make(jax.random.fold_in(key, layer + 1), layer_leaves(s))
+def layer_weights(s, key, layer):
+    """One layer's leaves of the family's shape ``s``, bf16 (``layer``
+    may be traced)."""
+    return _make(jax.random.fold_in(key, layer + 1), s.layer_leaves())
 
 
-def global_weights(s: Shape, key):
-    return _make(jax.random.fold_in(key, 0), global_leaves(s))
+def global_weights(s, key):
+    return _make(jax.random.fold_in(key, 0), s.global_leaves())
 
 
-def _norm(s: Shape, w: dict, name: str) -> dict:
-    if s.norm == "layernorm":
-        return {"scale": w[f"{name}_w"], "bias": w[f"{name}_b"]}
-    return {"scale": w[f"{name}_w"]}
-
-
-def to_program(s: Shape, glob: dict, layers: dict, vocab_padded: int):
-    """The ``repro.models`` parameter tree (stacked layers, padded
-    vocabulary rows and columns set to zero)."""
-    L, D, H, KVH, hd = s.layers, s.d_model, s.heads, s.kv_heads, s.head_dim
-    pad = vocab_padded - s.vocab
-    block = {
-        "ln1": _norm(s, layers, "ln1"),
-        "attn": {"wq": layers["q"].reshape(L, D, H, hd),
-                 "wk": layers["k"].reshape(L, D, KVH, hd),
-                 "wv": layers["v"].reshape(L, D, KVH, hd),
-                 "wo": layers["o"].reshape(L, H, hd, D)},
-        "ln2": _norm(s, layers, "ln2"),
-        "mlp": {"w_gate": layers["gate"], "w_up": layers["up"],
-                "w_down": layers["down"]},
-    }
-    return {"embed": jnp.pad(glob["embed"], ((0, pad), (0, 0))),
-            "lm_head": jnp.pad(glob["head"], ((0, 0), (0, pad))),
-            "final_norm": _norm(s, glob, "final"),
-            "layers": {"b0": block}}
-
-
-def program_params(s: Shape, seed: int, model):
+def program_params(s, seed: int, model):
     """Every weight the program serves, made on the device in one jitted
     call, in ``repro.models``' layout.  The layout is checked against
     the model's own abstract parameters: a mismatch is an error."""
@@ -124,8 +68,8 @@ def program_params(s: Shape, seed: int, model):
     def make(key):
         layers = jax.lax.map(lambda i: layer_weights(s, key, i),
                              jnp.arange(s.layers))
-        return to_program(s, global_weights(s, key), layers,
-                          model.cfg.vocab_padded)
+        return s.to_program(global_weights(s, key), layers,
+                            model.cfg.vocab_padded)
 
     want = model.abstract_params(jnp.bfloat16)
     got = jax.eval_shape(make, key)

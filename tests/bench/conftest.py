@@ -1,6 +1,7 @@
 """A toy-sized benchmark root for the CPU tests of the harness: the
 repository's registry models at toy widths, a fast fan-out mix, and the
-per-layer readers of the real benchmark."""
+per-layer readers, serving builders and model families of the real
+benchmark."""
 
 import json
 import shutil
@@ -55,7 +56,7 @@ def tiny_root(tmp_path_factory):
     bench = root / "bench"
     for d in ("configs", "traffic", "limits"):
         (bench / d).mkdir(parents=True)
-    for d in ("metrics", "backends"):
+    for d in ("metrics", "backends", "families"):
         shutil.copytree(ROOT / "bench" / d, bench / d)
     bm = json.loads((ROOT / "BENCHMARK.json").read_text())
     bm["configs"], bm["workloads"] = [], []

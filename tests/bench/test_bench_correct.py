@@ -55,6 +55,41 @@ def test_an_agent_loop_runs_from_data_alone(tiny_root):
     assert res["attempted"] == 4 and res["failed"] == 0
 
 
+# stablelm as published: rotary over the first quarter of each head, a
+# new file that reuses the dense family and overrides its one step
+QUARTER_ROTARY = '''"""The dense decoder, rotating a quarter of each head."""
+import jax.numpy as jnp
+
+from bench.families import dense
+
+
+class Shape(dense.Shape):
+    def rope(self, x, pos):
+        r = x.shape[-1] // 4
+        return jnp.concatenate([super().rope(x[..., :r], pos), x[..., r:]],
+                               axis=-1)
+'''
+
+
+@pytest.mark.parametrize("family,correct", [("dense", True),
+                                            ("quarter_rotary", False)])
+def test_the_family_the_configuration_names_decides(tiny_root, tiny_config,
+                                                    family, correct):
+    """The program rotates the whole head: scored by the dense family it
+    is correct, by a quarter-rotary family (one new file) it is not."""
+    (tiny_root / "bench/families/quarter_rotary.py").write_text(
+        QUARTER_ROTARY)
+    (tiny_root / f"bench/configs/tiny-stablelm-{family}.json").write_text(
+        json.dumps({**tiny_config("stablelm"), "family": family}))
+    res = run_tiny(tiny_root, "tiny-stablelm.fanout",
+                   entry={"config": f"tiny-stablelm-{family}"})
+    assert res["correct"] is correct, res["checks"]
+    gap = res["checks"]["logit_gap"]
+    assert (gap["value"] <= gap["limit"]) is correct
+    assert all(c["value"] <= c["limit"] for k, c in res["checks"].items()
+               if k != "logit_gap")
+
+
 def test_altered_token_is_caught(tiny_root):
     def fault(engine, backend, dispatcher):
         step = engine._decode_once

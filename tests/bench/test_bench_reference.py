@@ -1,13 +1,13 @@
-"""The seeded weights and the plain reference (bench/weights.py,
-bench/reference.py) at toy widths on the CPU."""
+"""The seeded weights and the plain reference of the dense family
+(bench/weights.py, bench/families/dense.py) at toy widths on the CPU."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference, spec, weights
-from bench.cost import Shape
+from bench import spec, weights
+from bench.families.dense import Shape
 
 
 @pytest.fixture(params=["stablelm", "yi"])
@@ -15,8 +15,8 @@ def tiny(request, tiny_config):
     from repro.models import build_model
     conf = tiny_config(request.param)
     conf["name"] = request.param
-    cfg = spec.model_config(conf, strict=False)
-    return conf, Shape.from_config(conf), build_model(cfg)
+    s = Shape.from_config(conf)
+    return conf, s, build_model(spec.model_config(conf, s, strict=False))
 
 
 def test_served_weights_are_the_reference_weights(tiny):
@@ -47,7 +47,7 @@ def test_reference_is_the_models_forward(tiny):
     with jax.default_matmul_precision("highest"):
         logits, _ = m32.forward(params, {"tokens": jnp.asarray(toks)})
     rows = [(0, p) for p in range(40)]
-    ref = reference.logits_at(s, seed, [list(toks[0])], rows)
+    ref = s.logits_at(seed, [list(toks[0])], rows)
     np.testing.assert_allclose(np.asarray(logits)[0, :, :s.vocab], ref,
                                rtol=1e-4, atol=1e-4)
 

@@ -108,3 +108,14 @@ def test_a_mix_the_generator_cannot_read_is_named(tmp_path):
     path.write_text(json.dumps(mix))
     faults = spec.validate(spec.load_benchmark(tmp_path), tmp_path)
     assert any("positions, the engine holds 4096" in f for f in faults)
+
+
+def test_a_missing_family_is_named(tmp_path):
+    copy_benchmark(tmp_path)
+    path = tmp_path / "bench/configs/yi-34b-8l.json"
+    conf = json.loads(path.read_text())
+    conf["family"] = "sparse_experts"
+    path.write_text(json.dumps(conf))
+    faults = spec.validate(spec.load_benchmark(tmp_path), tmp_path)
+    assert faults == ["yi-34b-8l.fanout: no family file for "
+                      "'sparse_experts'"]
