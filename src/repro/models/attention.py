@@ -364,20 +364,24 @@ def init_paged_kv_cache(cfg, num_pages, page_size, dtype):
     }
 
 
-def paged_decode_attention(cfg, p, x, cache, positions, page_table):
-    """One-token decode over paged KV: x [B,1,D]; cache k/v pools
-    [P,ps,KVH,hd]; positions [B] (index of the current token);
+def paged_decode_attention(cfg, p, x, pools, layer, positions, page_table):
+    """One-token decode over paged KV at layer ``layer`` (int or traced
+    scalar) of the stacked pools: x [B,1,D]; pools k/v
+    [L,P,ps,KVH,hd]; positions [B] (index of the current token);
     page_table [B,N] int32 — entry n holds the pool page storing positions
-    [n·ps, (n+1)·ps).  Returns (out [B,1,D], new_cache).
+    [n·ps, (n+1)·ps).  Returns (out [B,1,D], new_pools).
 
-    The current token's K/V is scatter-written into page
-    ``table[b, pos // ps]`` at offset ``pos % ps`` (always a slot-private
+    The current token's K/V is scatter-written into layer ``layer``, page
+    ``table[b, pos // ps]``, at offset ``pos % ps`` (always a slot-private
     page: shared prefix pages are full by construction, so decode never
     writes into them).  Retired slots point every table entry at the
     reserved scratch page 0, where their dead writes land harmlessly.
+    Nothing here takes the layer's slice of the pool, so a caller that
+    carries the donated pools through its layer loop updates them in
+    place (DESIGN.md §3.3).
     """
     B = x.shape[0]
-    ps = cache["k"].shape[1]
+    ps = pools["k"].shape[2]
     N = page_table.shape[1]
     q = _project_q(cfg, p, x)
     k, v = _project_kv(cfg, p, x)
@@ -396,29 +400,36 @@ def paged_decode_attention(cfg, p, x, cache, positions, page_table):
         page_table, jnp.minimum(positions // ps, N - 1)[:, None], axis=1
     )[:, 0]
     offs = positions % ps
-    new_cache = {
-        "k": cache["k"].at[page_ids, offs].set(k[:, 0].astype(cache["k"].dtype)),
-        "v": cache["v"].at[page_ids, offs].set(v[:, 0].astype(cache["v"].dtype)),
+    new_pools = {
+        "k": pools["k"].at[layer, page_ids, offs].set(
+            k[:, 0].astype(pools["k"].dtype)),
+        "v": pools["v"].at[layer, page_ids, offs].set(
+            v[:, 0].astype(pools["v"].dtype)),
     }
     lengths = positions + 1
     impl = cfg.attention_impl
     if impl.startswith("pallas"):
+        # the kernel takes one layer's pool: this slice is a per-layer
+        # copy until the kernel takes the layer index as an operand
         from repro.kernels.paged_attention import ops as pa_ops
         out = pa_ops.paged_decode_attention(
-            q, new_cache["k"], new_cache["v"], page_table, lengths,
-            interpret=(impl == "pallas_interpret"))
+            q, new_pools["k"][layer], new_pools["v"][layer], page_table,
+            lengths, interpret=(impl == "pallas_interpret"))
     else:
         # XLA gather fallback: dense [B, N·ps] view of the referenced
-        # pages + the contiguous path's mha_reference — with N·ps equal to
-        # the contiguous capacity and an identical validity mask, the
-        # logits are bitwise those of the contiguous engine
-        ck = new_cache["k"][page_table].reshape(B, N * ps, -1, cfg.head_dim)
-        cv = new_cache["v"][page_table].reshape(B, N * ps, -1, cfg.head_dim)
+        # pages, gathered straight from the stacked pool, + the
+        # contiguous path's mha_reference — with N·ps equal to the
+        # contiguous capacity and an identical validity mask, the logits
+        # are bitwise those of the contiguous engine
+        ck = new_pools["k"][layer, page_table].reshape(
+            B, N * ps, -1, cfg.head_dim)
+        cv = new_pools["v"][layer, page_table].reshape(
+            B, N * ps, -1, cfg.head_dim)
         valid = jnp.arange(N * ps)[None, :] < lengths[:, None]
         out = mha_reference(q, ck, cv, mask=valid[:, None, None, :])
     out = shard_hint(out, "act_qkv")
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-    return out, new_cache
+    return out, new_pools
 
 
 def cross_attention_cache(cfg, p, enc_out):

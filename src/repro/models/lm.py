@@ -340,19 +340,25 @@ def decode_step_paged(cfg, params, cache, tokens, positions, page_table):
     [B], page_table [B,N] int32 (shared by every layer — pages are
     allocated per sequence, and each layer's pool leaf stores that
     sequence's pages at the same ids).  Returns (logits [B,V],
-    new_cache)."""
+    new_cache).
+
+    The stacked pools ride in the layer loop's carry and each layer
+    scatters its new token into them at its own index: with the pools
+    donated, the step updates them in place and copies no layer of them
+    (DESIGN.md §3.3)."""
     h = params["embed"].astype(cfg.activation_dtype)[tokens]
     h = shard_hint(h, "act_hidden")
     group_kinds, n_groups, _ = _layer_groups(cfg)
 
-    def group_fn(h, inp):
-        gp, gcache = inp
-        new_caches = {}
+    def group_fn(carry, inp):
+        h, pools = carry
+        gp, layer = inp
+        pools = dict(pools)
         for i, kind in enumerate(group_kinds):
             p = gp[f"b{i}"]
-            a, nc = att.paged_decode_attention(
+            a, pools[f"b{i}"] = att.paged_decode_attention(
                 cfg, p["attn"], apply_norm(cfg, p["ln1"], h),
-                gcache[f"b{i}"], positions, page_table)
+                pools[f"b{i}"], layer, positions, page_table)
             h = h + a
             x = apply_norm(cfg, p["ln2"], h)
             if kind == "attn_moe":
@@ -360,23 +366,19 @@ def decode_step_paged(cfg, params, cache, tokens, positions, page_table):
             else:
                 m = mlpmod.apply_mlp(cfg, p["mlp"], x)
             h = h + m
-            new_caches[f"b{i}"] = nc
-        return h, new_caches
+        return (h, pools), None
 
+    carry = (h, cache["layers"])
     if cfg.scan_layers:
-        h, new_stacked = jax.lax.scan(
-            group_fn, h, (params["layers"], cache["layers"]))
+        carry, _ = jax.lax.scan(
+            group_fn, carry, (params["layers"], jnp.arange(n_groups)))
     else:
-        new_list = []
         for i in range(n_groups):
-            h, nc = group_fn(h, (params["layers"][f"g{i}"],
-                                 jax.tree.map(lambda c: c[i],
-                                              cache["layers"])))
-            new_list.append(nc)
-        new_stacked = _stack_cache(new_list)
+            carry, _ = group_fn(carry, (params["layers"][f"g{i}"], i))
+    h, pools = carry
     h = apply_norm(cfg, params["final_norm"], h)
     logits = logits_from_hidden(cfg, params, h)
-    return logits[:, 0], {"layers": new_stacked}
+    return logits[:, 0], {"layers": pools}
 
 
 # ---------------------------------------------------------------------------

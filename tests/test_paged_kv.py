@@ -3,8 +3,11 @@ page-granular radix trie (zero-copy sharing, pinning, LRU), and engine
 admission backpressure — outputs must stay token-exact through all of it."""
 
 import asyncio
+import dataclasses
+import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -381,3 +384,101 @@ def test_untraced_generate_allocates_no_spans(served):
     assert len(asyncio.run(go())) == 4
     assert spans_mod.SPAN_ALLOCS == before
     assert engine.stats()["host_syncs"] > 0
+
+
+# ---------------------------------------------------------------------------
+# the paged decode step (DESIGN.md §3.3)
+
+# 4 slots over 4 pages of 4 each (capacity 16) in a pool of 12 + scratch
+# page 0.  Slots 0-2 share page 1, slots 1-2 also page 3 (both full);
+# each live slot's current position falls in a private page; slot 3 is
+# retired (every entry scratch) and writes into page 0.
+_TABLE = np.array([[1, 2, 0, 0], [1, 3, 4, 5], [1, 3, 6, 0], [0, 0, 0, 0]],
+                  np.int32)
+_POS = np.array([5, 14, 8, 6], np.int32)
+_LIVE = 3
+_PS, _PAGES = 4, 13
+
+
+def _paged_case(scan_layers):
+    cfg = dataclasses.replace(get_config("stablelm-3b").reduced(),
+                              scan_layers=scan_layers)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(5))
+    shapes = model.init_paged_cache(_PAGES, _PS)
+    keys = iter(jax.random.split(jax.random.PRNGKey(6), 8))
+    pools = jax.tree.map(
+        lambda a: jax.random.normal(next(keys), a.shape, a.dtype), shapes)
+    toks = jnp.array([[11], [23], [37], [41]], jnp.int32)
+    return model, params, pools, toks
+
+
+@pytest.mark.parametrize("scan_layers", [True, False])
+def test_paged_decode_step_copies_no_pool(scan_layers):
+    """With the pools donated, as the engine donates them, the compiled
+    step writes them in place: no op makes a pool-sized buffer (the
+    stacked pool or one layer of it) other than the scatter itself."""
+    model, params, pools, toks = _paged_case(scan_layers)
+    stacked = pools["layers"]["b0"]["k"].shape
+    step = jax.jit(model.decode_step_paged, donate_argnums=(1,))
+    hlo = step.lower(params, pools, toks, jnp.asarray(_POS),
+                     jnp.asarray(_TABLE)).compile().as_text()
+    pool_shapes = {",".join(map(str, stacked)),
+                   ",".join(map(str, stacked[1:]))}
+    moves = [line.strip() for line in hlo.splitlines()
+             if (m := re.search(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(", line))
+             and m.group(1) in pool_shapes
+             and m.group(2) in ("copy", "copy-done", "broadcast",
+                                "concatenate", "dynamic-update-slice",
+                                "dynamic-slice", "slice")]
+    assert not moves, moves
+
+
+@pytest.mark.parametrize("scan_layers", [True, False])
+def test_paged_decode_step_equals_contiguous(scan_layers):
+    """The paged step's logits are bitwise those of the contiguous step
+    over the same K/V laid out contiguously, and the returned pools
+    differ from the given ones only at each layer's written rows."""
+    model, params, pools, toks = _paged_case(scan_layers)
+    pos, table = jnp.asarray(_POS), jnp.asarray(_TABLE)
+    # contiguous [L, B, N·ps, KVH, hd]: each slot's pages in table order
+    cont = jax.tree.map(
+        lambda a: a[:, table].reshape(a.shape[0], *table.shape[:1], -1,
+                                      *a.shape[3:]), pools)
+    logits_c, cont_new = jax.jit(model.decode_step)(params, cont, toks, pos)
+    logits_p, pools_new = jax.jit(model.decode_step_paged)(
+        params, pools, toks, pos, table)
+    np.testing.assert_array_equal(np.asarray(logits_p)[:_LIVE],
+                                  np.asarray(logits_c)[:_LIVE])
+
+    page_ids = _TABLE[np.arange(len(_POS)), _POS // _PS]
+    offs = _POS % _PS
+    written = np.zeros((_PAGES, _PS), bool)
+    written[page_ids, offs] = True
+    for name in ("k", "v"):
+        old = np.asarray(pools["layers"]["b0"][name])
+        new = np.asarray(pools_new["layers"]["b0"][name])
+        np.testing.assert_array_equal(new[:, ~written], old[:, ~written])
+        assert not np.array_equal(new[:, written], old[:, written])
+        c = np.asarray(cont_new["layers"]["b0"][name])
+        for b in range(_LIVE):
+            np.testing.assert_array_equal(new[:, page_ids[b], offs[b]],
+                                          c[:, b, _POS[b]])
+
+
+def test_paged_decode_step_pallas_matches_xla():
+    """The Pallas branch takes its layer of the carried pools and agrees
+    with the XLA gather; both write the same rows (past layer 0 the
+    written values carry the two attentions' rounding)."""
+    model, params, pools, toks = _paged_case(True)
+    pos, table = jnp.asarray(_POS), jnp.asarray(_TABLE)
+    lx, px = jax.jit(model.decode_step_paged)(params, pools, toks, pos,
+                                              table)
+    m_p = build_model(model.cfg.replace(attention_impl="pallas_interpret"))
+    lp, pp = jax.jit(m_p.decode_step_paged)(params, pools, toks, pos, table)
+    np.testing.assert_allclose(np.asarray(lp)[:_LIVE],
+                               np.asarray(lx)[:_LIVE], rtol=5e-4, atol=5e-4)
+    for a, b in zip(jax.tree.leaves(pp), jax.tree.leaves(px)):
+        np.testing.assert_array_equal(np.asarray(a)[0], np.asarray(b)[0])
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-4)
